@@ -1,0 +1,12 @@
+"""The benchmark's own tests run on the CPU at tiny sizes:
+
+    python -m pytest chipbench/tests -q -p no:cacheprovider
+"""
+import os
+import sys
+
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+for p in (ROOT, os.path.join(ROOT, "src")):
+    if p not in sys.path:
+        sys.path.insert(0, p)
