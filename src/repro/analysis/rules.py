@@ -10,10 +10,12 @@ refer to them:
     ``repro/obs``.  All wall timing flows through ``obs.timed`` /
     ``obs.clock`` so published numbers share one fenced idiom.
 ``FNC002 unfenced-timing``
-    A ``with obs.timed(...)`` / ``tracer.span(...)`` region that
-    dispatches jax work but never fences (``sw.fence`` /
-    ``obs.device_sync`` / ``jax.block_until_ready``) before the clock
-    stops — it measures Python dispatch, not device time.
+    A ``with obs.timed(...)`` region that dispatches jax work but never
+    fences (``sw.fence`` / ``obs.device_sync`` /
+    ``jax.block_until_ready``) before the clock stops — it measures
+    Python dispatch, not device time.  Spans (``obs.span`` /
+    ``tracer.span``) are host phases by design and never fence: a
+    fence inside one would make a traced run another program.
 ``FNC003 tracer-leak``
     Host conversions (``float()`` / ``int()`` / ``bool()`` /
     ``.item()`` / ``np.asarray``) or Python ``if``/``while`` on traced
@@ -206,10 +208,7 @@ def _timed_withitem(item: ast.withitem) -> bool:
     if not isinstance(call, ast.Call):
         return False
     name = dotted(call.func)
-    if name is None:
-        return False
-    leaf = name.rsplit(".", 1)[-1]
-    return leaf in ("timed", "span")
+    return name is not None and name.rsplit(".", 1)[-1] == "timed"
 
 
 def _is_dispatch(call: ast.Call) -> bool:

@@ -31,6 +31,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.engine.defaults import DEFAULT_CHUNK_L
 
 from . import packets as pkt
@@ -82,46 +83,48 @@ def _packetize(client_params: Sequence[Any], cfg: FedNCConfig
     produces a few Python floats of metadata each — but the byte/symbol
     packetization itself is always the single batched pass."""
     engine = engine_for(cfg)
-    if cfg.quantize_bits:
-        qspecs, qtrees = [], []
-        for p in client_params:
-            q, qs = pkt.quantize_pytree(p, bits=cfg.quantize_bits)
-            qtrees.append(q)
-            qspecs.append(qs)
-        P, spec = engine.packetize(qtrees)
-        return P, spec, qspecs
-    P, spec = engine.packetize(client_params)
-    return P, spec, None
+    with obs.span("fednc.packetize", cat="fednc"):
+        if cfg.quantize_bits:
+            qspecs, qtrees = [], []
+            for p in client_params:
+                q, qs = pkt.quantize_pytree(p, bits=cfg.quantize_bits)
+                qtrees.append(q)
+                qspecs.append(qs)
+            P, spec = engine.packetize(qtrees)
+            return P, spec, qspecs
+        P, spec = engine.packetize(client_params)
+        return P, spec, None
 
 
 def _aggregate(P_hat: jnp.ndarray, spec: pkt.PacketSpec,
                weights: Sequence[float], cfg: FedNCConfig,
                qspecs: Optional[list] = None) -> Any:
     """Decoded packets -> weighted FedAvg aggregate (paper §II-A)."""
-    K = P_hat.shape[0]
-    w = np.asarray(weights, np.float32)
-    w = w / w.sum()
-    stacked = pkt.packets_to_pytrees(P_hat, spec)
-    if qspecs is not None:
-        trees = [jax.tree_util.tree_map(lambda x, k=k: x[k], stacked)
-                 for k in range(K)]
-        trees = [pkt.dequantize_pytree(t, qs)
-                 for t, qs in zip(trees, qspecs, strict=True)]
+    with obs.span("fednc.aggregate", cat="fednc"):
+        K = P_hat.shape[0]
+        w = np.asarray(weights, np.float32)
+        w = w / w.sum()
+        stacked = pkt.packets_to_pytrees(P_hat, spec)
+        if qspecs is not None:
+            trees = [jax.tree_util.tree_map(lambda x, k=k: x[k], stacked)
+                     for k in range(K)]
+            trees = [pkt.dequantize_pytree(t, qs)
+                     for t, qs in zip(trees, qspecs, strict=True)]
+            return jax.tree_util.tree_map(
+                lambda *xs: sum(
+                    wk * jnp.asarray(x, jnp.float32)
+                    for wk, x in zip(w, xs, strict=True)
+                ).astype(xs[0].dtype),
+                *trees,
+            )
+        # weighted sum over the stacked client axis, term order matching
+        # fedavg_round's sequential sum so FedNC == FedAvg stays bit-exact
         return jax.tree_util.tree_map(
-            lambda *xs: sum(
-                wk * jnp.asarray(x, jnp.float32)
-                for wk, x in zip(w, xs, strict=True)
-            ).astype(xs[0].dtype),
-            *trees,
+            lambda x: sum(
+                wk * jnp.asarray(x[k], jnp.float32) for k, wk in enumerate(w)
+            ).astype(x.dtype),
+            stacked,
         )
-    # weighted sum over the stacked client axis, term order matching
-    # fedavg_round's sequential sum so FedNC == FedAvg stays bit-exact
-    return jax.tree_util.tree_map(
-        lambda x: sum(
-            wk * jnp.asarray(x[k], jnp.float32) for k, wk in enumerate(w)
-        ).astype(x.dtype),
-        stacked,
-    )
 
 
 def packetize_clients(client_params: Sequence[Any], cfg: FedNCConfig
@@ -175,16 +178,21 @@ def decode_and_aggregate(batch: EncodedBatch, spec: pkt.PacketSpec,
 def fednc_round(client_params: Sequence[Any], weights: Sequence[float],
                 prev_global: Any, cfg: FedNCConfig, key,
                 channel=None) -> RoundResult:
-    """Full Alg.-1 round: a thin adapter over CodingEngine.round()."""
-    engine = engine_for(cfg)
-    P, spec, qspecs = _packetize(client_params, cfg)
-    K = P.shape[0]
-    out = engine.round(P, key, channel=channel)
-    del P   # free the payload before the decoded copy is unpacketized
-    if not out.ok:
-        return RoundResult(prev_global, False, out.report, 0)
-    agg = _aggregate(out.packets, spec, weights, cfg, qspecs=qspecs)
-    return RoundResult(agg, True, out.report, K)
+    """Full Alg.-1 round: a thin adapter over CodingEngine.round().
+
+    Each call leaves one ``fednc.round`` record in ``repro.obs``: the
+    host time of its phases (``fednc.packetize``, ``engine.*``,
+    ``fednc.sync``, ``fednc.aggregate``) and its counts."""
+    with obs.record("fednc.round"):
+        engine = engine_for(cfg)
+        P, spec, qspecs = _packetize(client_params, cfg)
+        K = P.shape[0]
+        out = engine.round(P, key, channel=channel)
+        del P   # free the payload before the decoded copy is unpacketized
+        if not out.ok:
+            return RoundResult(prev_global, False, out.report, 0)
+        agg = _aggregate(out.packets, spec, weights, cfg, qspecs=qspecs)
+        return RoundResult(agg, True, out.report, K)
 
 
 def fedavg_round(client_params: Sequence[Any], weights: Sequence[float],

@@ -68,6 +68,15 @@ def _is_seed_rows(A) -> bool:
     return arr.ndim == 1 and arr.dtype == jnp.uint32
 
 
+def _on_host(x, read=bool):
+    """``read(x)`` of a device value: the round blocks here until the
+    device has computed `x`.  Each such wait is a ``fednc.sync`` span
+    and counts in ``fednc.syncs``."""
+    with obs.span("fednc.sync", cat="engine"):
+        obs.count("fednc.syncs")
+        return read(x)
+
+
 def _chunk_plan(lanes: int, width: int, wrap=None):
     """Cut `lanes` payload lanes into `width`-lane chunks.
 
@@ -281,14 +290,13 @@ class CodingEngine:
         i+1 is enqueued while chunk i still executes on-device.  On a
         seeded engine, pass the (n,) uint32 seed vector as `A` to run
         the seeded encode kernel (rows regenerated in-kernel).
-        `stage` labels the per-chunk trace spans (``engine.<stage>``)
-        when tracing is enabled.
+        `stage` names the stream's host span (``engine.<stage>``).
         """
         return self._stream(A, P, enc_seeded=_is_seed_rows(A),
                             stage=stage)
 
     def _stream(self, A, P, A_post=None, *, enc_seeded: bool = False,
-                stage: str = "encode", post_stage: str = "decode"):
+                stage: str = "encode"):
         """Run the kernel chunk-by-chunk over the lane dim of P.
 
         With `A_post` (the decode mixing matrix), each chunk is pushed
@@ -303,51 +311,45 @@ class CodingEngine:
         so the coding matrix never rides along with the payload.  The
         `A_post` mixing (decode) product always uses the materialized
         kernel.
+
+        One host span, ``engine.<stage>``, covers slicing, dispatching
+        and joining every chunk; it never waits on the device.
         """
-        enc_kernel, shards = self._mesh_kernel(enc_seeded)
-        post_kernel, _ = self._mesh_kernel(False)
-        s = self.config.s
-        if A_post is not None:
-            n_out = A_post.shape[0]
-        else:
-            n_out = A.shape[0]
-        L = P.shape[1]
-        if L == 0:
-            return jnp.zeros((n_out, 0), jnp.uint8)
+        with obs.span(f"engine.{stage}", cat="engine"):
+            enc_kernel, shards = self._mesh_kernel(enc_seeded)
+            post_kernel, _ = self._mesh_kernel(False)
+            s = self.config.s
+            if A_post is not None:
+                n_out = A_post.shape[0]
+            else:
+                n_out = A.shape[0]
+            L = P.shape[1]
+            if L == 0:
+                return jnp.zeros((n_out, 0), jnp.uint8)
 
-        tr = obs.get_tracer()
+            def mm(kernel, M, X):
+                self._dispatches.inc()
+                return kernel(M, X, s=s) if shards == 1 else kernel(M, X)
 
-        def mm(kernel, M, X, label, chunk):
-            self._dispatches.inc()
-            if not tr.enabled:
-                return kernel(M, X, s=s) if shards == 1 \
-                    else kernel(M, X)
-            # traced: fence the chunk so the span measures device time
-            # (the untraced path above keeps async-dispatch pipelining)
-            with tr.span(f"engine.{label}", cat="engine",
-                         chunk=chunk) as sp:
-                return sp.fence(kernel(M, X, s=s) if shards == 1
-                                else kernel(M, X))
-
-        cl, nc = self._chunks(L)
-        if nc == 1 and L % shards == 0:
-            out = mm(enc_kernel, A, P, stage, 0)
-            return mm(post_kernel, A_post, out, post_stage, 0) \
-                if A_post is not None else out
-        if shards > 1:
-            Lp = L + (-L) % shards
-            place, nc, take, join = self._lane_chunking(
-                Lp // shards, -(-cl // shards))
-            P = place(jnp.pad(P, ((0, 0), (0, Lp - L))) if Lp != L else P)
-        else:
-            nc, take, join = _chunk_plan(L, cl)
-        outs = []
-        for c in range(nc):
-            enc = mm(enc_kernel, A, take(P, c), stage, c)
-            outs.append(mm(post_kernel, A_post, enc, post_stage, c)
-                        if A_post is not None else enc)
-        out = join(*outs)
-        return out[:, :L] if out.shape[1] != L else out
+            cl, nc = self._chunks(L)
+            if nc == 1 and L % shards == 0:
+                out = mm(enc_kernel, A, P)
+                return mm(post_kernel, A_post, out) \
+                    if A_post is not None else out
+            if shards > 1:
+                Lp = L + (-L) % shards
+                place, nc, take, join = self._lane_chunking(
+                    Lp // shards, -(-cl // shards))
+                P = place(jnp.pad(P, ((0, 0), (0, Lp - L))) if Lp != L else P)
+            else:
+                nc, take, join = _chunk_plan(L, cl)
+            outs = []
+            for c in range(nc):
+                enc = mm(enc_kernel, A, take(P, c))
+                outs.append(mm(post_kernel, A_post, enc)
+                            if A_post is not None else enc)
+            out = join(*outs)
+            return out[:, :L] if out.shape[1] != L else out
 
     # -- pipeline stages --------------------------------------------------
 
@@ -439,10 +441,8 @@ class CodingEngine:
         """Pick K independent tuples out of n >= K, fully on-device."""
         if isinstance(batch, SeededBatch):
             batch = batch.expand(self.config.s)
-        with obs.get_tracer().span("engine.select", cat="engine",
-                                   n=int(batch.n)) as sp:
+        with obs.span("engine.select", cat="engine", n=int(batch.n)):
             ok, idx, _ = incremental_select(batch.A, self.config.s)
-            sp.fence(idx)
         return ok, EncodedBatch(A=batch.A[idx], C=batch.C[idx])
 
     def decode(self, batch) -> tuple[bool, Optional[jnp.ndarray]]:
@@ -471,11 +471,9 @@ class CodingEngine:
         ok = jnp.bool_(True)
         if batch.n > K:
             ok, batch = self.select(batch)
-        with obs.get_tracer().span("engine.invert", cat="engine",
-                                   K=K) as sp:
+        with obs.span("engine.invert", cat="engine", K=K):
             ok_inv, A_inv = invert(self.field, batch.A)
-            sp.fence(A_inv)
-        if not bool(ok & ok_inv):
+        if not _on_host(ok & ok_inv):
             return False, None
         return True, self.matmul(A_inv, batch.C, stage="decode")
 
@@ -521,16 +519,16 @@ class CodingEngine:
             return False, None, None
         ok, idx, _ = incremental_select(batch.A, self.config.s)
         ok_inv, A_inv = invert(self.field, batch.A[idx])
-        if not bool(ok & ok_inv):
+        if not _on_host(ok & ok_inv):
             return False, None, None
         P_hat = self.matmul(A_inv, batch.C[idx], stage="decode")
-        red = np.setdiff1d(np.arange(n), np.asarray(idx))
+        red = np.setdiff1d(np.arange(n), _on_host(idx, np.asarray))
         if red.size == 0:
             return True, P_hat, None
         red_j = jnp.asarray(red, jnp.int32)
         pred = self.matmul(batch.A[red_j], P_hat, stage="verify")
-        pred_np = np.asarray(pred)
-        got_np = np.asarray(batch.C[red_j])
+        pred_np = _on_host(pred, np.asarray)
+        got_np = _on_host(batch.C[red_j], np.asarray)
         verified = all(
             hashlib.sha256(pred_np[i].tobytes()).digest()
             == hashlib.sha256(got_np[i].tobytes()).digest()
@@ -551,27 +549,24 @@ class CodingEngine:
         n, K = A.shape
         if n < K:
             return EngineRound(False, None, None)
-        tr = obs.get_tracer()
         ok = jnp.bool_(True)
         if n > K:
-            with tr.span("engine.select", cat="engine", n=n) as sp:
+            with obs.span("engine.select", cat="engine", n=n):
                 ok, idx, _ = incremental_select(A, self.config.s)
-                sp.fence(idx)
             A_sel = A[idx]
             enc = seeds[idx] if seeds is not None else A_sel
         else:
             A_sel = A
             enc = seeds if seeds is not None else A
-        with tr.span("engine.invert", cat="engine", K=K) as sp:
+        with obs.span("engine.invert", cat="engine", K=K):
             ok_inv, A_inv = invert(self.field, A_sel)
-            sp.fence(A_inv)
-        if not bool(ok & ok_inv):
+        if not _on_host(ok & ok_inv):
             return EngineRound(False, None, None)
         # encode only the selected rows — the ideal channel delivers
         # everything, so unselected erasure-headroom rows are dead work
         # and A_inv·(A_sel·P) is the exact decode.
         P_hat = self._stream(enc, P, A_post=A_inv,
-                             enc_seeded=seeds is not None)
+                             enc_seeded=seeds is not None, stage="decode")
         return EngineRound(True, P_hat, None)
 
     def _expand_err(self, err_seeds, which, width: int) -> jnp.ndarray:
@@ -605,29 +600,26 @@ class CodingEngine:
         """
         n, K = A.shape
         L = P.shape[1]
-        tr = obs.get_tracer()
         idx_np = np.asarray(plan.idx, np.int64)
-        with tr.span("engine.transform", cat="engine", n=n) as sp:
+        with obs.span("engine.transform", cat="engine", n=n):
             A_rx = A
             if plan.m and plan.row_seeds is not None:
                 idx_t = jnp.asarray(idx_np, jnp.int32)
                 A_err = self._expand_err(plan.row_seeds,
                                          np.arange(plan.m), K)
                 A_rx = A.at[idx_t].set(A[idx_t] ^ A_err)
-            sp.fence(A_rx)
-        with tr.span("engine.select", cat="engine", n=n) as sp:
+        with obs.span("engine.select", cat="engine", n=n):
             ok, sel, _ = incremental_select(A_rx, self.config.s)
-            sp.fence(sel)
-        report = ChannelReport(n, n, bool(ok))
-        if not bool(ok):
+        ok = _on_host(ok)
+        report = ChannelReport(n, n, ok)
+        if not ok:
             return EngineRound(False, None, report)
-        with tr.span("engine.invert", cat="engine", K=K) as sp:
+        with obs.span("engine.invert", cat="engine", K=K):
             _, A_inv = invert(self.field, A_rx[sel])
-            sp.fence(A_inv)
-        sel_np = np.asarray(sel, np.int64)
+        sel_np = _on_host(sel, np.asarray).astype(np.int64)
         enc_rows = seeds if seeds is not None else A
         P_hat = self._stream(enc_rows[sel], P, A_post=A_inv,
-                             enc_seeded=seeds is not None)
+                             enc_seeded=seeds is not None, stage="decode")
         pos_of = {int(r): j for j, r in enumerate(idx_np)}
 
         def err_at(rows: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
@@ -658,7 +650,7 @@ class CodingEngine:
                         C_red = C_red.at[w].set(C_red[w] ^ E)
                 resid = self._stream(A_rx[red_j], P_hat,
                                      stage="verify") ^ C_red
-                verified = not bool(jnp.any(resid != 0))
+                verified = not _on_host(jnp.any(resid != 0))
         return EngineRound(True, P_hat, report, verified)
 
     def _fused_channel_round(self, P: jnp.ndarray, A: jnp.ndarray,
@@ -679,13 +671,12 @@ class CodingEngine:
         """
         n, K = A.shape
         s = self.config.s
-        tr = obs.get_tracer()
         plan = channel.plan_transform(n, s)
         if isinstance(plan, RowTamper):
             # byzantine corruption: the whole round (including the
             # redundant-rank cross-check) has its own fused tail
             return self._fused_tamper_round(P, A, plan, seeds, verify)
-        with tr.span("engine.transform", cat="engine", n=n) as sp:
+        with obs.span("engine.transform", cat="engine", n=n):
             if isinstance(plan, RowGather):
                 delivered = int(len(plan.idx))
                 if delivered < K:
@@ -699,16 +690,14 @@ class CodingEngine:
             else:
                 raise TypeError(
                     f"unsupported channel plan {type(plan).__name__}")
-            sp.fence(A_rx)
-        with tr.span("engine.select", cat="engine", n=delivered) as sp:
+        with obs.span("engine.select", cat="engine", n=delivered):
             ok, sel, _ = incremental_select(A_rx, s)
-            sp.fence(sel)
-        report = ChannelReport(n, delivered, bool(ok))
-        if not bool(ok):
+        ok = _on_host(ok)
+        report = ChannelReport(n, delivered, ok)
+        if not ok:
             return EngineRound(False, None, report)
-        with tr.span("engine.invert", cat="engine", K=K) as sp:
+        with obs.span("engine.invert", cat="engine", K=K):
             _, A_inv = invert(self.field, A_rx[sel])  # sel independent
-            sp.fence(A_inv)
         if isinstance(plan, RowGather):
             A_enc, A_post = A[idx[sel]], A_inv
             if seeds is not None:
@@ -721,7 +710,7 @@ class CodingEngine:
             if seeds is not None:
                 A_enc = seeds
         P_hat = self._stream(A_enc, P, A_post=A_post,
-                             enc_seeded=seeds is not None)
+                             enc_seeded=seeds is not None, stage="decode")
         return EngineRound(True, P_hat, report)
 
     def _stagewise_channel_round(self, P: jnp.ndarray, A: jnp.ndarray,
@@ -780,8 +769,7 @@ class CodingEngine:
         """
         K, L = P.shape
         n = K + self.config.extra_tuples
-        with obs.get_tracer().span("engine.round", cat="engine",
-                                   K=K, L=L, n=n) as sp:
+        with obs.span("engine.round", cat="engine", K=K, L=L, n=n):
             if self.seeded:
                 # seeded engine: draw 4-byte row seeds; the tiny
                 # expansion drives row-space planning while the L-sized
@@ -793,7 +781,6 @@ class CodingEngine:
             else:
                 A = self.coding_matrix(key, n, K)
                 out = self._run_round(P, A, channel, verify=verify)
-            sp.fence(out.packets)
         return out
 
     # -- the fused hierarchical round (paper §III) ------------------------
@@ -852,13 +839,10 @@ class CodingEngine:
         """
         K, L = P.shape
         n_out = [len(ids) + spare_per_edge for ids in edges]
-        with obs.get_tracer().span("engine.multi_edge_round",
-                                   cat="engine", K=K, L=L,
-                                   edges=len(edges)) as sp:
+        with obs.span("engine.multi_edge_round", cat="engine", K=K, L=L,
+                      edges=len(edges)):
             A = self.multi_edge_coding_matrix(key, edges, K, n_out)
-            out = self._run_round(P, A, wan_channel, verify=verify)
-            sp.fence(out.packets)
-        return out
+            return self._run_round(P, A, wan_channel, verify=verify)
 
 
 @functools.lru_cache(maxsize=None)

@@ -436,7 +436,7 @@ class DecoderBank:
         return rows, seeds, use_seed, valid, C
 
     def ingest(self, rows=None, seeds=None, use_seed=None, valid=None,
-               C=None, *, batched: bool = True) -> np.ndarray:
+               C=None, *, batched: bool = True) -> jax.Array | np.ndarray:
         """Advance every slot by one padded (slots, g) tick block.
 
         `rows` (slots, g, K) uint8 materialized coding rows, `seeds`
@@ -444,7 +444,9 @@ class DecoderBank:
         selector per tuple, `valid` (slots, g) bool padding mask, `C`
         (slots, g, L) uint8 payloads; omitted arrays default to zeros
         (and `valid` to all-true).  Returns the (slots, g) rank-after-
-        each-arrival trajectory.
+        each-arrival trajectory: on the batched path a device array
+        that the caller reads when it needs it (the read waits for the
+        scan), so the dispatch itself never blocks.
 
         ``batched=True`` advances all slots in ONE vmapped dispatch;
         ``batched=False`` runs the identical per-slot kernel once per
@@ -460,7 +462,7 @@ class DecoderBank:
                 self._B, self._Y, self._filled, rows, seeds, use_seed,
                 valid, C, mask)
             self.dispatches += 1
-            return np.asarray(ranks)
+            return ranks
         ranks = np.zeros(valid.shape, np.int32)
         work = np.asarray(jnp.any(valid, axis=1))
         base = self.rank

@@ -19,6 +19,8 @@ from __future__ import annotations
 from bisect import bisect_right
 from typing import Optional, Sequence
 
+from repro.obs import ring
+
 #: schema tag every snapshot carries (validated by scripts/check_bench.py)
 METRICS_SCHEMA = "fednc-metrics-v1"
 
@@ -55,6 +57,7 @@ class Counter:
 
     def inc(self, n: int = 1) -> None:
         self.value += n
+        ring.count(self.name, n)
 
     def snapshot(self) -> dict:
         return {"type": self.kind, "value": self.value}

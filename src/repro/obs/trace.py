@@ -4,27 +4,27 @@ Every wall-clock number this repo publishes used to come from an
 ad-hoc ``time.perf_counter()`` pair; this module replaces that with
 two primitives sharing one clock:
 
-* :class:`Tracer` — an *enabled* tracer records spans
-  (:meth:`Tracer.span`, Chrome ``"X"`` complete events), instants
-  (``"i"``) and counter samples (``"C"``) into an in-memory event
-  list that saves as Chrome trace-event JSON (open it in Perfetto /
-  ``chrome://tracing``).  The module-level active tracer defaults to
-  :data:`NULL_TRACER`, whose ``span()`` returns a shared no-op
-  context manager — no timestamps are taken, no events allocated, so
-  hot paths (per-chunk kernel dispatches, per-tick server loops) pay
-  essentially nothing when tracing is off.
+* :meth:`Tracer.span` — a span marks a *host phase*: the time the
+  host spends in a block of the program.  It never blocks on device
+  work, so a round dispatches the same programs in the same order with
+  the same host syncs whether tracing is on or off; device time comes
+  from the profiler's device trace.  Every span, enabled tracer or
+  not, enters ``jax.profiler.TraceAnnotation(<name>)`` (which puts it
+  on the profiler's host plane, on the device trace's clock, and costs
+  next to nothing when no profile is being taken) and adds its host
+  nanoseconds to the open per-call record (:mod:`repro.obs.ring`).
+  An *enabled* :class:`Tracer` also keeps it, with instants (``"i"``)
+  and counter samples (``"C"``), as Chrome trace events that save as
+  trace-event JSON (open it in Perfetto / ``chrome://tracing``).  The
+  module-level active tracer defaults to :data:`NULL_TRACER`, which
+  keeps no events.
 * :func:`timed` — an always-on stopwatch for the wall numbers call
   sites need regardless of tracing (``RoundLog.wall_s``, grid cell
-  walls, benchmark loops).  When the active tracer is enabled the
-  same measurement also lands in the trace as a span, so enabling
-  tracing never changes *what* is measured, only whether it is
-  recorded.
-
-Spans fence device work before the clock stops
-(:meth:`Span.fence` -> :func:`device_sync` ->
-``jax.block_until_ready``), so a traced span measures *device* time
-— JAX's async dispatch otherwise returns control to Python with the
-kernel still in flight and the span would under-report.
+  walls, benchmark loops).  ``sw.fence(out)`` blocks on device work
+  before the clock stops (:func:`device_sync` ->
+  ``jax.block_until_ready``), so the wall covers the device work the
+  block dispatched.  When the active tracer is enabled the same
+  measurement also lands in the trace as a span.
 
 Event timestamps are epoch-anchored microseconds
 (``time_ns`` offset measured once per process against
@@ -40,6 +40,8 @@ import pathlib
 import threading
 import time
 from typing import Optional
+
+from repro.obs import ring
 
 #: schema tag recorded in the trace document's ``otherData``
 TRACE_SCHEMA = "fednc-trace-v1"
@@ -75,28 +77,22 @@ def device_sync(x):
 
 
 class Span:
-    """One traced section: ``with tracer.span("engine.encode") as sp``.
+    """One host phase: ``with obs.span("engine.select"):``.
 
-    ``sp.fence(out)`` registers device output to block on before the
-    clock stops (so the span measures device time, not dispatch time);
-    ``sp.dur_s`` holds the duration after exit."""
+    Reads the clock at entry and exit and never blocks on device work.
+    ``sp.dur_s`` holds the duration after exit; ``sp.set(**args)``
+    attaches args to the Chrome event of an enabled tracer."""
 
-    __slots__ = ("_tracer", "name", "cat", "args", "_t0", "_pending",
+    __slots__ = ("_tracer", "name", "cat", "args", "_t0", "_ann",
                  "dur_s")
 
-    def __init__(self, tracer: "Tracer", name: str, cat: str,
+    def __init__(self, tracer: "Optional[Tracer]", name: str, cat: str,
                  args: dict):
         self._tracer = tracer
         self.name = name
         self.cat = cat
         self.args = args
-        self._pending = None
         self.dur_s = 0.0
-
-    def fence(self, x):
-        """Block on `x` (device work) just before the span closes."""
-        self._pending = x
-        return x
 
     def set(self, **args) -> "Span":
         """Attach/override span args from inside the block."""
@@ -104,50 +100,43 @@ class Span:
         return self
 
     def __enter__(self) -> "Span":
+        self._ann = _annotation(self.name)
+        self._ann.__enter__()
         self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
-        if self._pending is not None:
-            device_sync(self._pending)
-            self._pending = None
         t1 = time.perf_counter_ns()
+        self._ann.__exit__(exc_type, exc, tb)
         self.dur_s = (t1 - self._t0) / 1e9
-        self._tracer._complete(self.name, self.cat, self._t0, t1,
-                               self.args)
+        ring.add_span(self.name, t1 - self._t0)
+        if self._tracer is not None:
+            self._tracer._complete(self.name, self.cat, self._t0, t1,
+                                   self.args)
         return False
 
 
-class _NullSpan:
-    """The shared do-nothing span :data:`NULL_TRACER` hands out."""
-
-    __slots__ = ()
-    dur_s = 0.0
-
-    def fence(self, x):
-        return x
-
-    def set(self, **args) -> "_NullSpan":
-        return self
-
-    def __enter__(self) -> "_NullSpan":
-        return self
-
-    def __exit__(self, exc_type, exc, tb) -> bool:
-        return False
+_TraceAnnotation = None
 
 
-_NULL_SPAN = _NullSpan()
+def _annotation(name: str):
+    """``jax.profiler.TraceAnnotation(name)`` (jax imported on first
+    use, so importing repro.obs does not import jax)."""
+    global _TraceAnnotation
+    if _TraceAnnotation is None:
+        from jax.profiler import TraceAnnotation as _TraceAnnotation
+    return _TraceAnnotation(name)
 
 
 class NullTracer:
-    """Disabled tracer: every call is a no-op, no time is read."""
+    """Disabled tracer: spans still mark host phases (profiler
+    annotation, per-call records) but no Chrome event is kept."""
 
     enabled = False
     events: tuple = ()
 
-    def span(self, name: str, cat: str = "", **args) -> _NullSpan:
-        return _NULL_SPAN
+    def span(self, name: str, cat: str = "", **args) -> Span:
+        return Span(None, name, cat, args)
 
     def instant(self, name: str, cat: str = "", **args) -> None:
         pass
@@ -272,6 +261,11 @@ _active: "Tracer | NullTracer" = NULL_TRACER
 def get_tracer():
     """The process-wide active tracer (NULL_TRACER unless enabled)."""
     return _active
+
+
+def span(name: str, cat: str = "", **args) -> Span:
+    """A host-phase span on the active tracer (see :class:`Span`)."""
+    return _active.span(name, cat, **args)
 
 
 def set_tracer(tracer):

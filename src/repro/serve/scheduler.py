@@ -31,13 +31,15 @@ class FifoScheduler:
         self._q: list[deque] = [deque() for _ in range(self.slots)]
 
     def enqueue(self, slot: int, *, seed: int, payload,
-                row=None) -> None:
+                row=None, stamp: float = 0.0) -> None:
         """Queue one coded tuple for `slot`.
 
         `row` is the materialized (k,) coding row for the materialized
         wire format, or None for the seeded format (the 4-byte `seed`
         is expanded in-dispatch).  `payload` is the (l,) coded symbols;
         both are zero-padded here to the bank-wide (K,)/(L,) shapes.
+        `stamp` (``obs.clock()`` at offer) rides with the tuple to the
+        block that drains it.
         """
         use = row is None
         r = np.zeros((self.K,), np.uint8)
@@ -47,7 +49,7 @@ class FifoScheduler:
         c = np.zeros((self.L,), np.uint8)
         payload = np.asarray(payload, np.uint8)
         c[: payload.shape[0]] = payload
-        self._q[int(slot)].append((r, np.uint32(seed), use, c))
+        self._q[int(slot)].append((r, np.uint32(seed), use, c, stamp))
 
     def queue_depth(self, slot: int) -> int:
         return len(self._q[int(slot)])
@@ -66,12 +68,14 @@ class FifoScheduler:
         self._q[int(slot)].clear()
         return n
 
-    def next_block(self):
+    def next_block(self, stamps: list | None = None):
         """Drain <= g_tick tuples per slot into one padded tick block.
 
         Returns ``(rows, seeds, use_seed, valid, C)`` with shapes
         ``(slots, g_tick, K) / (slots, g_tick) x3 / (slots, g_tick, L)``
         ready for `DecoderBank.ingest`, or None if every queue is empty.
+        The drained tuples' enqueue stamps are appended to `stamps`
+        when it is given.
         """
         if self.pending == 0:
             return None
@@ -84,7 +88,9 @@ class FifoScheduler:
         for j in range(J):
             q = self._q[j]
             for p in range(min(g, len(q))):
-                r, sd, u, c = q.popleft()
+                r, sd, u, c, t = q.popleft()
+                if stamps is not None:
+                    stamps.append(t)
                 rows[j, p] = r
                 seeds[j, p] = sd
                 use[j, p] = u

@@ -149,8 +149,9 @@ class DecodeServer:
         self._slot_job[slot] = job
         st.slot = slot
         self._m_busy.set(int(np.sum(self._slot_job >= 0)))
-        for seed, row, payload in st.backlog:
-            self.sched.enqueue(slot, seed=seed, payload=payload, row=row)
+        for seed, row, payload, stamp in st.backlog:
+            self.sched.enqueue(slot, seed=seed, payload=payload, row=row,
+                               stamp=stamp)
         st.backlog.clear()
 
     def offer(self, job: int, payload, *, seed: int = 0,
@@ -160,17 +161,20 @@ class DecodeServer:
         `row=None` means the seeded wire format (expand `seed`
         in-dispatch); a materialized (k,) `row` means the classic
         format.  Returns False if the job already completed (the
-        packet is dropped and counted in ``late_dropped``)."""
+        packet is dropped and counted in ``late_dropped``).  The packet
+        is stamped with ``obs.clock()``: the tick that ingests it
+        records how long it waited (``serve.queue_wait_s``)."""
         st = self._jobs[int(job)]
         if st.done is not None:
             self._m_late.inc()
             return False
         st.offered += 1
+        stamp = obs.clock()
         if st.slot is None:
-            st.backlog.append((int(seed), row, payload))
+            st.backlog.append((int(seed), row, payload, stamp))
         else:
             self.sched.enqueue(st.slot, seed=seed, payload=payload,
-                               row=row)
+                               row=row, stamp=stamp)
         return True
 
     def result(self, job: int) -> np.ndarray:
@@ -193,54 +197,71 @@ class DecodeServer:
 
     def tick(self) -> bool:
         """One scheduler tick: drain queues, one ingest dispatch,
-        emit completions, admit waiting jobs.  False if idle."""
-        tr = obs.get_tracer()
+        emit completions, admit waiting jobs.  False if idle.
+
+        A busy tick leaves one ``serve.tick`` record in ``repro.obs``:
+        its host phases (``serve.schedule``, ``serve.ingest``,
+        ``serve.readback``, ``serve.complete``), the valid packets
+        (``serve.packets_ingested``) and capacity
+        (``serve.block_capacity``) of its block, and each ingested
+        packet's wait from offer to the end of the tick
+        (``serve.queue_wait_s``)."""
         depth = self.sched.pending
         if depth == 0:
             return False
+        with obs.record("serve.tick"):
+            self._tick(depth)
+        return True
+
+    def _tick(self, depth: int) -> None:
+        tr = obs.get_tracer()
         self._m_depth.set(depth)
         self._m_busy.set(int(np.sum(self._slot_job >= 0)))
         if tr.enabled:
             tr.counter("serve.queue_depth", depth)
             tr.counter("serve.slots_busy",
                        int(np.sum(self._slot_job >= 0)))
-        block = self.sched.next_block()
-        if block is None:                      # pragma: no cover
-            return False
-        rows, seeds, use, valid, C = block
+        stamps: list = []
+        with obs.span("serve.schedule", cat="serve"):
+            rows, seeds, use, valid, C = self.sched.next_block(stamps)
         batch = int(valid.sum())
-        with tr.span("serve.ingest", cat="serve", packets=batch) as sp:
-            ranks = sp.fence(self.bank.ingest(
+        with obs.span("serve.ingest", cat="serve", packets=batch):
+            ranks = self.bank.ingest(
                 rows=rows, seeds=seeds, use_seed=use, valid=valid, C=C,
-                batched=self.batched))
+                batched=self.batched)
+        with obs.span("serve.readback", cat="serve"):
+            ranks = np.asarray(ranks)
         self._m_ticks.inc()
         self._m_ingested.inc(batch)
+        obs.count("serve.block_capacity", valid.size)
         self._m_batch.observe(batch)
-        freed = []
-        for slot in np.nonzero(valid.any(axis=1))[0]:
-            job = int(self._slot_job[slot])
-            st = self._jobs[job]
-            if st.done is None and (ranks[slot] >= st.k).any():
-                p0 = int(np.argmax(ranks[slot] >= st.k))
-                arrivals = st.arrivals + int(valid[slot, : p0 + 1].sum())
-                st.payload = np.asarray(self.bank.payload(slot))
-                latency = obs.clock() - st.t_submit
-                st.done = JobCompletion(
-                    job=job, k=st.k, l=st.l, arrivals=arrivals,
-                    latency_s=latency,
-                    payload_sha=payload_digest(st.payload))
-                self._m_latency.observe(latency)
-                tr.instant("serve.complete", cat="serve", job=job,
-                           arrivals=arrivals)
-                self._m_late.inc(self.sched.clear(slot))
-                self.bank.close(slot)
-                self._slot_job[slot] = -1
-                freed.append(slot)
-            st.arrivals += int(valid[slot].sum())
-        for slot in freed:
-            if self._waiting:
-                self._place(self._waiting.popleft(), int(slot))
-        return True
+        with obs.span("serve.complete", cat="serve"):
+            freed = []
+            for slot in np.nonzero(valid.any(axis=1))[0]:
+                job = int(self._slot_job[slot])
+                st = self._jobs[job]
+                if st.done is None and (ranks[slot] >= st.k).any():
+                    p0 = int(np.argmax(ranks[slot] >= st.k))
+                    arrivals = st.arrivals + int(valid[slot, : p0 + 1].sum())
+                    st.payload = np.asarray(self.bank.payload(slot))
+                    latency = obs.clock() - st.t_submit
+                    st.done = JobCompletion(
+                        job=job, k=st.k, l=st.l, arrivals=arrivals,
+                        latency_s=latency,
+                        payload_sha=payload_digest(st.payload))
+                    self._m_latency.observe(latency)
+                    tr.instant("serve.job_done", cat="serve", job=job,
+                               arrivals=arrivals)
+                    self._m_late.inc(self.sched.clear(slot))
+                    self.bank.close(slot)
+                    self._slot_job[slot] = -1
+                    freed.append(slot)
+                st.arrivals += int(valid[slot].sum())
+            for slot in freed:
+                if self._waiting:
+                    self._place(self._waiting.popleft(), int(slot))
+        obs.sample("serve.queue_wait_s",
+                   (obs.clock() - np.asarray(stamps)).tolist())
 
     def drain(self, max_ticks: int = 1_000_000) -> int:
         """Tick until every queue is empty; returns ticks run."""

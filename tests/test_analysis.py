@@ -83,6 +83,14 @@ def test_fnc002_clean_when_fenced():
     assert rules_at("benchmarks/bench_x.py", src) == []
 
 
+def test_fnc002_clean_on_spans():
+    """Spans are host phases by design: they never fence, and the rule
+    looks only at ``obs.timed`` walls."""
+    src = _TIMED.replace('obs.timed("matmul") as sw',
+                         'obs.span("matmul")').format(tail="")
+    assert rules_at("src/repro/engine/x.py", src) == []
+
+
 def test_fnc002_clean_when_region_does_no_jax_work():
     src = """\
     from repro import obs

@@ -1,19 +1,27 @@
 """repro.obs: trace round-trip, metric merge laws, no-op neutrality.
 
 The observability layer must never change what it observes: the
-NULL_TRACER path has to be bit-exact with the traced path, snapshots
-must merge associatively (grid workers reduce in arbitrary order),
-and the serialized artifacts must stay valid Chrome trace-event JSON
-(the contract scripts/check_bench.py re-checks standalone).
+NULL_TRACER path has to be bit-exact with the traced path and run
+the same programs and host syncs, snapshots must merge associatively
+(grid workers reduce in arbitrary order), the serialized artifacts
+must stay valid Chrome trace-event JSON (the contract
+scripts/check_bench.py re-checks standalone), and every entry call
+leaves one per-call record whose phases the chip benchmark reads.
 """
+import glob
 import json
+import os
 import random
+import sys
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro import obs
+from repro.core.channel import ErasureChannel
+from repro.core.fednc import FedNCConfig, fednc_round
 from repro.core.gf import get_field
 from repro.engine import CodingEngine, EngineConfig
 from repro.serve import poisson_multitenant_trace, serve_trace
@@ -218,9 +226,10 @@ def test_engine_round_bit_exact_tracing_on_and_off():
     assert off.ok and on.ok
     np.testing.assert_array_equal(np.asarray(off.packets),
                                   np.asarray(on.packets))
-    # the traced run really did record the per-stage spans
+    # the traced run really did record the per-stage spans (the fused
+    # encode -> decode stream is one engine.decode span)
     names = {e["name"] for e in tr.events}
-    assert {"engine.round", "engine.encode", "engine.invert"} <= names
+    assert {"engine.round", "engine.decode", "engine.invert"} <= names
 
 
 def test_serve_trace_bit_exact_tracing_on_and_off():
@@ -254,9 +263,10 @@ def test_serve_metrics_snapshot_is_published():
 
 def test_disabled_tracer_overhead_under_2pct_of_serve_smoke():
     """The instrumentation bar: with tracing off, the per-call cost of
-    the no-op span/instant/counter paths, times the number of events a
-    traced smoke replay actually emits, must stay under 2% of that
-    replay's wall time."""
+    the span/instant/counter paths and of the per-call record path (a
+    record opened, a span, a count and a sample landed in it), times
+    the number of events a traced smoke replay actually emits, must
+    stay under 2% of that replay's wall time."""
     trace = poisson_multitenant_trace(6, 8, 64, s=8, rate=4.0,
                                       extra_packets=3, seed=5)
     serve_trace(trace, slots=4, g_tick=4, batched=True)   # jit warmup
@@ -273,15 +283,166 @@ def test_disabled_tracer_overhead_under_2pct_of_serve_smoke():
     n = 100_000
     with obs.timed("overhead.null_span", tracer=None) as sw:
         for _ in range(n):
-            with null.span("x", cat="t", i=0):
-                pass
+            with obs.record("overhead.x"):
+                with null.span("x", cat="t", i=0):
+                    obs.count("x")
+                obs.sample("x", (0.0,))
             null.instant("x")
             null.counter("x", 1)
-    per_call = sw.dur_s / n            # one span + instant + counter
+    per_call = sw.dur_s / n      # record + span + count + sample + ...
     overhead = per_call * n_events
     assert overhead < 0.02 * off.wall_s, (
         f"no-op instrumentation {overhead * 1e6:.1f}us vs "
         f"{off.wall_s * 1e3:.1f}ms replay ({n_events} events)")
+
+
+# ---------------------------------------------------------------------------
+# Host phases and per-call records
+# ---------------------------------------------------------------------------
+
+
+def _tiny_clients(K=4):
+    keys = jax.random.split(jax.random.PRNGKey(3), K)
+    return [{"w": jax.random.normal(k, (37,), jnp.float32),
+             "b": jnp.arange(5, dtype=jnp.bfloat16) * (i + 1)}
+            for i, k in enumerate(keys)]
+
+
+_ROUNDS = {
+    # (FedNCConfig kwargs, channel factory)
+    "erasure": ({"kernel_impl": "jnp_packed", "extra_tuples": 2,
+                 "chunk_l": 64}, lambda: ErasureChannel(0.2, seed=5)),
+    "ideal_seeded": ({"kernel_impl": "jnp_packed_seeded", "chunk_l": 64},
+                     lambda: None),
+}
+
+
+def _round(case, clients, seed=0):
+    kw, channel = _ROUNDS[case]
+    K = len(clients)
+    return fednc_round(clients, [1.0 / K] * K, clients[0],
+                       FedNCConfig(**kw), jax.random.PRNGKey(seed),
+                       channel=channel())
+
+
+@pytest.mark.parametrize("case", sorted(_ROUNDS))
+def test_tracing_runs_the_same_programs_and_host_syncs(case, monkeypatch):
+    """A traced and an untraced round make the same number of
+    `jax.block_until_ready` calls, host reads of device values and
+    kernel dispatches: no code path is taken only when tracing is on."""
+    from jax._src.array import ArrayImpl
+
+    from repro.core.fednc import engine_for
+
+    calls = {"block_until_ready": 0, "host_read": 0}
+    block, value = jax.block_until_ready, ArrayImpl._value
+
+    def counting_block(x):
+        calls["block_until_ready"] += 1
+        return block(x)
+
+    def counting_value(self):
+        calls["host_read"] += 1
+        return value.fget(self)
+
+    clients = _tiny_clients()
+    eng = engine_for(FedNCConfig(**_ROUNDS[case][0]))
+    _round(case, clients)                      # compile outside the count
+    monkeypatch.setattr(jax, "block_until_ready", counting_block)
+    monkeypatch.setattr(ArrayImpl, "_value", property(counting_value))
+    seen = {}
+    for traced in (False, True):
+        obs.set_tracer(obs.Tracer() if traced else obs.NULL_TRACER)
+        calls.update(block_until_ready=0, host_read=0)
+        d0 = eng.dispatch_count
+        res = _round(case, clients, seed=1)
+        assert res.decoded
+        rec = obs.last_records("fednc.round", 1)[0]
+        seen[traced] = (dict(calls), eng.dispatch_count - d0,
+                        rec.counts, sorted(rec.spans))
+    assert seen[False] == seen[True]
+    assert seen[False][2]["fednc.syncs"] >= 1
+    assert seen[False][2]["engine.dispatches"] == seen[False][1] >= 2
+
+
+def test_span_lands_on_the_profiler_host_plane(tmp_path):
+    """Spans are TraceAnnotations: a CPU profile of a round holds them by
+    name, as the chip benchmark's trace reader sees them."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from chipbench import profile
+
+    clients = _tiny_clients()
+    _round("erasure", clients)                 # compile outside the trace
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        _round("erasure", clients, seed=1)
+    finally:
+        jax.profiler.stop_trace()
+    pb = glob.glob(f"{tmp_path}/**/*.xplane.pb", recursive=True)
+    trace = profile.read(pb[0], span_prefix="fednc.")
+    names = {n for n, _, _ in trace.spans}
+    assert {"fednc.packetize", "fednc.sync", "fednc.aggregate"} <= names
+    assert all(e > s for _, s, e in trace.spans)
+
+
+@pytest.mark.parametrize("case", sorted(_ROUNDS))
+def test_fednc_round_leaves_one_record_per_call(case):
+    clients = _tiny_clients()
+    t0 = obs.clock()
+    for seed in range(3):
+        _round(case, clients, seed=seed)
+    recs = [r for r in obs.records("fednc.round") if r.t0 >= t0]
+    assert len(recs) == 3
+    phases = ("fednc.packetize", "engine.round", "engine.decode",
+              "fednc.sync", "fednc.aggregate")
+    for rec in recs:
+        assert all(rec.spans[p] > 0 for p in phases), rec.spans
+        assert rec.t1 > rec.t0
+        assert rec.span_ms("fednc.packetize", "fednc.aggregate") \
+            < 1e3 * (rec.t1 - rec.t0)
+        assert rec.counts["fednc.syncs"] >= 1
+
+
+def test_record_ring_is_bounded_and_nested_entries_open_one_record():
+    with obs.record("outer.call") as outer:
+        _round("erasure", _tiny_clients())     # a nested entry point
+        with obs.record("inner.call") as inner:
+            obs.count("inner.items", 2)
+    assert inner is outer
+    last = obs.records()[-1]
+    assert last is outer and last.name == "outer.call"
+    assert last.counts["inner.items"] == 2
+    assert last.spans["fednc.packetize"] > 0   # the round's phases
+    assert obs.records("inner.call") == []
+    for _ in range(obs.RECORDS_MAX + 10):
+        with obs.record("filler.call"):
+            pass
+    assert len(obs.records()) == obs.RECORDS_MAX
+    assert obs.last_records("filler.call", obs.RECORDS_MAX) is not None
+    assert obs.last_records("outer.call", 1) is None     # fell out
+
+
+def test_serve_replay_leaves_one_record_per_tick():
+    trace = poisson_multitenant_trace(4, 6, 32, s=8, rate=4.0,
+                                      extra_packets=2, seed=3)
+    t0 = obs.clock()
+    rep = serve_trace(trace, slots=4, g_tick=4, batched=True)
+    recs = [r for r in obs.records("serve.tick") if r.t0 >= t0]
+    assert len(recs) == rep.ticks
+    valid = sum(r.counts["serve.packets_ingested"] for r in recs)
+    capacity = sum(r.counts["serve.block_capacity"] for r in recs)
+    waits = [w for r in recs for w in r.samples["serve.queue_wait_s"]]
+    assert valid == rep.packets_ingested == len(waits)
+    assert capacity == rep.ticks * 4 * 4 >= valid
+    assert min(waits) >= 0.0
+    for r in recs:
+        assert len(r.samples["serve.queue_wait_s"]) \
+            == r.counts["serve.packets_ingested"]
+        assert all(r.spans[p] > 0 for p in (
+            "serve.schedule", "serve.ingest", "serve.readback",
+            "serve.complete"))
 
 
 # ---------------------------------------------------------------------------
