@@ -24,6 +24,8 @@ the iid/no-loss setting.
 """
 from __future__ import annotations
 
+import functools
+import math
 from dataclasses import dataclass
 from typing import Any, Optional, Sequence
 
@@ -96,18 +98,57 @@ def _packetize(client_params: Sequence[Any], cfg: FedNCConfig
         return P, spec, None
 
 
+@functools.partial(jax.jit, static_argnums=3)
+def _weighted_mean(P_hat: jnp.ndarray, w: jnp.ndarray, zero: jnp.ndarray,
+                   spec: pkt.PacketSpec) -> Any:
+    """Decoded (K, L) symbols -> the FedAvg aggregate, as one program.
+
+    Compiled once per (spec, K): the weights are traced, so new weights
+    reuse it.  Per leaf, a loop over the clients in order reads client
+    k's leaf from its row (``packets.symbols_to_leaf``) and adds
+    w[k]·x_k to a float32 accumulator that starts at 0; the sum is cast
+    back to the leaf's dtype.  That is the arithmetic and order of
+    ``fedavg_round``, so FedNC == FedAvg bit for bit.  The program holds
+    one float32 leaf, never a stacked or per-client copy of the tree,
+    and the loop (not an unrolled chain) keeps its size independent of K.
+
+    `zero` is a float32 0 passed as data, where the compiler cannot see
+    it: it would fold 0 + x to x, which keeps a -0.0 that the sum from 0
+    makes +0.0.  Each product is xor-ed with its bits before the add,
+    or XLA's CPU backend contracts acc + w·x into one fused
+    multiply-add, which rounds once where FedAvg rounds twice."""
+    obs.count("fednc.aggregate_traces")
+    K = P_hat.shape[0]
+    zero_bits = jax.lax.bitcast_convert_type(zero, jnp.uint32)
+    out = []
+    for i, (shape, dtype) in enumerate(zip(spec.shapes, spec.dtypes,
+                                           strict=True)):
+        lo, hi = pkt.leaf_symbols(spec, i)
+
+        def add(k, acc, lo=lo, hi=hi, dtype=dtype):
+            sym = jax.lax.dynamic_slice(P_hat, (k, lo), (1, hi - lo))[0]
+            x = pkt.symbols_to_leaf(sym, dtype, spec.s).astype(jnp.float32)
+            wx = jax.lax.bitcast_convert_type(w[k] * x, jnp.uint32)
+            return acc + jax.lax.bitcast_convert_type(wx ^ zero_bits,
+                                                      jnp.float32)
+
+        acc = jnp.broadcast_to(zero, (math.prod(shape),))
+        acc = jax.lax.fori_loop(0, K, add, acc)
+        out.append(acc.astype(dtype).reshape(shape))
+    return jax.tree_util.tree_unflatten(spec.treedef, out)
+
+
 def _aggregate(P_hat: jnp.ndarray, spec: pkt.PacketSpec,
                weights: Sequence[float], cfg: FedNCConfig,
                qspecs: Optional[list] = None) -> Any:
     """Decoded packets -> weighted FedAvg aggregate (paper §II-A)."""
     with obs.span("fednc.aggregate", cat="fednc"):
-        K = P_hat.shape[0]
         w = np.asarray(weights, np.float32)
         w = w / w.sum()
-        stacked = pkt.packets_to_pytrees(P_hat, spec)
         if qspecs is not None:
+            stacked = pkt.packets_to_pytrees(P_hat, spec)
             trees = [jax.tree_util.tree_map(lambda x, k=k: x[k], stacked)
-                     for k in range(K)]
+                     for k in range(P_hat.shape[0])]
             trees = [pkt.dequantize_pytree(t, qs)
                      for t, qs in zip(trees, qspecs, strict=True)]
             return jax.tree_util.tree_map(
@@ -117,14 +158,8 @@ def _aggregate(P_hat: jnp.ndarray, spec: pkt.PacketSpec,
                 ).astype(xs[0].dtype),
                 *trees,
             )
-        # weighted sum over the stacked client axis, term order matching
-        # fedavg_round's sequential sum so FedNC == FedAvg stays bit-exact
-        return jax.tree_util.tree_map(
-            lambda x: sum(
-                wk * jnp.asarray(x[k], jnp.float32) for k, wk in enumerate(w)
-            ).astype(x.dtype),
-            stacked,
-        )
+        obs.count("fednc.aggregate_traces", 0)   # reads 0 when warm
+        return _weighted_mean(P_hat, w, np.float32(0), spec)
 
 
 def packetize_clients(client_params: Sequence[Any], cfg: FedNCConfig

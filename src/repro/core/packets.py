@@ -73,13 +73,13 @@ def symbols_to_bytes(sym: jnp.ndarray, s: int) -> jnp.ndarray:
     sym = jnp.asarray(sym, jnp.uint8)
     if s == 8:
         return sym
+    # strided slices, not a reshape to (n, 8 // s): the TPU would pad
+    # that minor dimension to 128 lanes
     per = 8 // s
-    sym = sym.reshape(-1, per)
-    shifts = jnp.arange(per, dtype=jnp.uint8) * s
-    return jax.lax.reduce(
-        (sym << shifts[None, :]).astype(jnp.uint8),
-        np.uint8(0), jax.lax.bitwise_or, (1,),
-    )
+    b = sym[0::per]
+    for j in range(1, per):
+        b = b | (sym[j::per] << np.uint8(j * s))
+    return b
 
 
 # ---------------------------------------------------------------------------
@@ -145,6 +145,24 @@ def packet_to_pytree(packet: jnp.ndarray, spec: PacketSpec):
         leaves.append(_bytes_to_leaf(b[off: off + nbytes], shape, dtype))
         off += nbytes
     return jax.tree_util.tree_unflatten(spec.treedef, leaves)
+
+
+def leaf_symbols(spec: PacketSpec, i: int) -> tuple[int, int]:
+    """Symbols [lo, hi) of a packet that hold leaf `i`."""
+    nbytes = [int(np.prod(sh, dtype=np.int64)) * jnp.dtype(dt).itemsize
+              for sh, dt in zip(spec.shapes[:i + 1], spec.dtypes[:i + 1])]
+    lo = sum(nbytes[:-1]) * spec.symbols_per_byte
+    return lo, lo + nbytes[-1] * spec.symbols_per_byte
+
+
+def symbols_to_leaf(sym: jnp.ndarray, dtype, s: int) -> jnp.ndarray:
+    """A leaf's symbols (its :func:`leaf_symbols` span) -> the leaf, flat.
+
+    Slices, shifts and a bitcast only, so traced code that consumes the
+    leaf fuses the whole inverse into its own pass."""
+    b = symbols_to_bytes(sym, s)
+    return _bytes_to_leaf(b, (b.shape[0] // jnp.dtype(dtype).itemsize,),
+                          dtype)
 
 
 # ---------------------------------------------------------------------------

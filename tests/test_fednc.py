@@ -42,6 +42,77 @@ def test_fednc_equals_fedavg_when_decodable(s):
                 np.asarray(res_avg.global_params[k]))
 
 
+def _flush(a):
+    """Float32 subnormals to signed zero: XLA flushes them, as operands
+    and as results, on the CPU as on the TPU (eager ops too)."""
+    a = np.asarray(a, np.float32)
+    return np.where(np.abs(a) < np.finfo(np.float32).tiny,
+                    np.copysign(np.float32(0), a), a).astype(np.float32)
+
+
+def _numpy_mean(xs, w):
+    """FedAvg's mean of one leaf: float32 from 0, client order, cast back."""
+    acc = np.float32(0)
+    for wk, x in zip(w, xs, strict=True):
+        acc = _flush(acc + _flush(wk * _flush(np.asarray(x, np.float32))))
+    return acc.astype(np.asarray(xs[0]).dtype)
+
+
+def _special_clients(K, dtype, seed):
+    """K trees of `dtype`: an odd-size leaf, a scalar leaf, and a leaf
+    that holds -0.0 in every client, subnormals, +inf in client 0 and
+    -inf in the last client."""
+    rng = np.random.default_rng(seed)
+    tiny = float(jnp.finfo(dtype).smallest_subnormal)
+    out = []
+    for k in range(K):
+        special = rng.normal(size=6).astype(np.float32)
+        special[0] = -0.0
+        special[1] = tiny * (k + 1)
+        special[2] = -tiny * 3
+        special[3] = np.inf if k == 0 else special[3]
+        special[4] = -np.inf if k == K - 1 else special[4]
+        out.append({"odd": jnp.asarray(rng.normal(size=(7, 3)), dtype),
+                    "scalar": jnp.asarray(rng.normal(), dtype),
+                    "special": jnp.asarray(special).astype(dtype)})
+    return out
+
+
+@pytest.mark.parametrize("s", [8, 4])
+@pytest.mark.parametrize("K", [1, 3, 8, 33])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16", "float16"])
+def test_compiled_aggregate_is_bit_exact(dtype, K, s):
+    """The compiled aggregate of decoded symbols equals the sequential
+    numpy FedAvg sum and `fedavg_round`, byte for byte."""
+    clients = _special_clients(K, jnp.dtype(dtype), seed=K * 10 + s)
+    weights = list(np.random.default_rng(K).uniform(0.1, 1.0, K))
+    P, spec = fednc.engine_for(FedNCConfig(s=s)).packetize(clients)
+    agg = fednc.aggregate_decoded(P, spec, weights, FedNCConfig(s=s))
+    avg = fednc.fedavg_round(clients, weights, clients[0]).global_params
+    w = np.asarray(weights, np.float32)
+    w = w / w.sum()
+    for name in ("odd", "scalar", "special"):
+        ref = _numpy_mean([c[name] for c in clients], w)
+        got = np.asarray(agg[name])
+        assert got.dtype == ref.dtype and got.shape == ref.shape
+        assert got.tobytes() == ref.tobytes(), (name, got, ref)
+        assert got.tobytes() == np.asarray(avg[name]).tobytes(), name
+
+
+def test_compiled_aggregate_builds_no_stacked_copy():
+    """The aggregate's scratch memory stays below K float32 copies of
+    the tree: no stacked or per-client float32 tree is materialized."""
+    K = 8
+    tree = {"w": jnp.zeros((64, 32), jnp.float32),
+            "b": jnp.zeros((32,), jnp.bfloat16),
+            "e": jnp.zeros((100, 16), jnp.float16)}
+    P, spec = fednc.engine_for(FedNCConfig(s=8)).packetize([tree] * K)
+    compiled = fednc._weighted_mean.lower(
+        P, np.full(K, 1 / K, np.float32), np.float32(0), spec).compile()
+    f32_bytes = 4 * sum(x.size for x in jax.tree_util.tree_leaves(tree))
+    assert compiled.memory_analysis().temp_size_in_bytes < K * f32_bytes
+
+
 def test_round_skip_keeps_previous_global():
     """Singular coding matrix -> Alg. 1 else-branch: w_t = w_{t-1}."""
     clients = _clients(4)

@@ -405,6 +405,25 @@ def test_fednc_round_leaves_one_record_per_call(case):
         assert rec.counts["fednc.syncs"] >= 1
 
 
+def test_aggregate_compiles_once_per_spec_and_cohort():
+    """`fednc.aggregate_traces` counts traces of the compiled aggregate:
+    1 for a new tree, 0 for new weights on the same tree and K."""
+    clients = [{"v": jnp.full((43,), i + 1.0, jnp.float32)}
+               for i in range(3)]
+    cfg = FedNCConfig(kernel_impl="jnp_packed", chunk_l=64)
+    other_tree = [{"v": c["v"][:41]} for c in clients]
+    traces = []
+    for weights, cs in (([1.0, 1.0, 1.0], clients),
+                        ([0.2, 0.3, 0.5], clients),
+                        ([1.0, 1.0, 1.0], other_tree)):
+        res = fednc_round(cs, weights, cs[0], cfg, jax.random.PRNGKey(7))
+        assert res.decoded
+        rec = obs.last_records("fednc.round", 1)[0]
+        assert rec.spans["fednc.aggregate"] > 0
+        traces.append(rec.counts["fednc.aggregate_traces"])
+    assert traces == [1, 0, 1]
+
+
 def test_record_ring_is_bounded_and_nested_entries_open_one_record():
     with obs.record("outer.call") as outer:
         _round("erasure", _tiny_clients())     # a nested entry point
