@@ -117,3 +117,21 @@ def test_roofline_share_from_kernel_time():
     assert roof.read(run) == pytest.approx(100 * 32e-6 / 0.15)
     run.trace = None
     assert roof.read(run) is None
+
+
+def test_roofline_counts_each_chips_share_of_the_bytes():
+    roof = _reader("gf_matmul_roofline")
+    s = profile.summarize(profile.read(FIXTURE), ["bench.round"],
+                          ["bench.round"], ["gf_matmul"])
+
+    def read(**chips):
+        return roof.read(harness.Run(
+            "c", {}, {}, {"rounds": 1, "K": 8, "L": 1 << 20, **chips}, 1.0,
+            None, {"hbm_bytes_per_s": 819e9}, s))
+
+    one = read()
+    # the one-chip reading as it was before chips were counted
+    assert one == 100.0 * (roof.round_bytes(8, 1 << 20) / 819e9) \
+        / (s.matched_s("gf_matmul") / 1)
+    assert read(chips=1) == one
+    assert read(chips=4) == pytest.approx(one / 4, rel=1e-12)
