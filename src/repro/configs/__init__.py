@@ -18,6 +18,7 @@ ARCHITECTURES = (
     "deepseek_v2_236b",
     "qwen2_72b",
     "qwen3_8b",
+    "deepseek_v2_lite",
 )
 
 # CLI ids (dashes) -> module names
@@ -31,6 +32,7 @@ _ALIASES.update({
     "qwen3-4b": "qwen3_4b",
     "arctic-480b": "arctic_480b",
     "deepseek-v2-236b": "deepseek_v2_236b",
+    "deepseek-v2-lite": "deepseek_v2_lite",
     "qwen2-72b": "qwen2_72b",
     "qwen3-8b": "qwen3_8b",
 })

@@ -25,7 +25,6 @@ the iid/no-loss setting.
 from __future__ import annotations
 
 import functools
-import math
 from dataclasses import dataclass
 from typing import Any, Optional, Sequence
 
@@ -118,24 +117,69 @@ def _weighted_mean(P_hat: jnp.ndarray, w: jnp.ndarray, zero: jnp.ndarray,
     or XLA's CPU backend contracts acc + w·x into one fused
     multiply-add, which rounds once where FedAvg rounds twice."""
     obs.count("fednc.aggregate_traces")
-    K = P_hat.shape[0]
+    return jax.tree_util.tree_unflatten(spec.treedef,
+                                        _leaf_means(P_hat, w, zero, spec))
+
+
+def _leaf_means(P_hat, w, zero, spec: pkt.PacketSpec) -> list:
+    """Each leaf of `spec` averaged over P_hat's rows (traced)."""
+    return [_mean_rows(P_hat, w, zero, *pkt.leaf_symbols(spec, i), dtype,
+                       spec.s).reshape(shape)
+            for i, (shape, dtype) in enumerate(zip(spec.shapes, spec.dtypes,
+                                                   strict=True))]
+
+
+def _mean_rows(P_hat, w, zero, lo: int, hi: int, dtype, s: int):
+    """Σ_k w[k]·x_k over P_hat's rows, x_k the flat leaf in symbols
+    [lo, hi) of row k: float32 from `zero` in row order, cast to
+    `dtype` (the arithmetic :func:`_weighted_mean` documents)."""
     zero_bits = jax.lax.bitcast_convert_type(zero, jnp.uint32)
-    out = []
-    for i, (shape, dtype) in enumerate(zip(spec.shapes, spec.dtypes,
-                                           strict=True)):
-        lo, hi = pkt.leaf_symbols(spec, i)
 
-        def add(k, acc, lo=lo, hi=hi, dtype=dtype):
-            sym = jax.lax.dynamic_slice(P_hat, (k, lo), (1, hi - lo))[0]
-            x = pkt.symbols_to_leaf(sym, dtype, spec.s).astype(jnp.float32)
-            wx = jax.lax.bitcast_convert_type(w[k] * x, jnp.uint32)
-            return acc + jax.lax.bitcast_convert_type(wx ^ zero_bits,
-                                                      jnp.float32)
+    def add(k, acc):
+        sym = jax.lax.dynamic_slice(P_hat, (k, lo), (1, hi - lo))[0]
+        x = pkt.symbols_to_leaf(sym, dtype, s).astype(jnp.float32)
+        wx = jax.lax.bitcast_convert_type(w[k] * x, jnp.uint32)
+        return acc + jax.lax.bitcast_convert_type(wx ^ zero_bits,
+                                                  jnp.float32)
 
-        acc = jnp.broadcast_to(zero, (math.prod(shape),))
-        acc = jax.lax.fori_loop(0, K, add, acc)
-        out.append(acc.astype(dtype).reshape(shape))
-    return jax.tree_util.tree_unflatten(spec.treedef, out)
+    n = (hi - lo) // (8 // s if s < 8 else 1) // jnp.dtype(dtype).itemsize
+    acc = jnp.broadcast_to(zero, (n,))
+    return jax.lax.fori_loop(0, P_hat.shape[0], add, acc).astype(dtype)
+
+
+@functools.partial(jax.jit, static_argnums=(4, 5))
+def _block_mean(P_hats: tuple, w: jnp.ndarray, zero: jnp.ndarray,
+                prev: tuple, spec: pkt.PacketSpec,
+                layout: pkt.BlockLayout) -> Any:
+    """Decoded generations -> the per-block FedAvg aggregate, as one
+    program per (layout, contributor sets).
+
+    Whole leaves are averaged over all K clients (``P_hats[0]``, weights
+    ``w[:K]``); block b of a group over its holders (its generation's
+    rows, weights the next K_b of `w`), each as :func:`_mean_rows`.  A
+    blocked leaf starts from its `prev` value, so a block nobody holds
+    keeps it."""
+    obs.count("fednc.aggregate_traces")
+    K = len(layout.held)
+    out = [None] * layout.treedef.num_leaves
+    for i, x in zip(layout.dense, _leaf_means(P_hats[0], w[:K], zero, spec),
+                    strict=True):
+        out[i] = x
+    prev = iter(prev)
+    for g in layout.groups:
+        for i in g.leaves:
+            out[i] = next(prev)
+    off = K
+    for P_hat, (gi, b) in zip(P_hats[1:], layout.generations(), strict=True):
+        g = layout.groups[gi]
+        w_b = w[off:off + len(g.holders[b])]
+        off += len(g.holders[b])
+        at = np.unravel_index(b, g.grid)
+        for i, (lo, hi), shape, dtype in zip(g.leaves, g.block_symbols(spec.s),
+                                             g.shapes, g.dtypes, strict=True):
+            x = _mean_rows(P_hat, w_b, zero, lo, hi, dtype, spec.s)
+            out[i] = out[i].at[at].set(x.reshape(shape))
+    return jax.tree_util.tree_unflatten(layout.treedef, out)
 
 
 def _aggregate(P_hat: jnp.ndarray, spec: pkt.PacketSpec,
@@ -210,15 +254,67 @@ def decode_and_aggregate(batch: EncodedBatch, spec: pkt.PacketSpec,
     return RoundResult(agg, True, None, K)
 
 
+def _block_round(layout: pkt.BlockLayout, dense: list, rows: list,
+                 weights: Sequence[float], prev_global: Any,
+                 cfg: FedNCConfig, key, channel) -> RoundResult:
+    """The round of partial updates (:func:`fednc_round`)."""
+    if cfg.quantize_bits:
+        raise NotImplementedError("partial updates are coded bit-exact")
+    if jax.tree_util.tree_structure(prev_global) != layout.treedef:
+        raise ValueError("prev_global's structure differs from the updates'")
+    engine = engine_for(cfg)
+    K = len(dense)
+    with obs.span("fednc.packetize", cat="fednc"):
+        P, spec = engine.packetize(dense)
+    with obs.span("fednc.blocks", cat="fednc"):
+        P_blocks = pkt.block_packets(rows, layout, cfg.s)
+    out = engine.round([P, *P_blocks], key, channel=channel)
+    del P, P_blocks     # decoded in place
+    if not out.ok:
+        return RoundResult(prev_global, False, out.report, 0)
+    with obs.span("fednc.aggregate", cat="fednc"):
+        w = np.asarray(weights, np.float32)
+        ws = [w / w.sum()]
+        for gi, b in layout.generations():
+            w_b = w[list(layout.groups[gi].holders[b])]
+            ws.append(w_b / w_b.sum())
+        prev = jax.tree_util.tree_leaves(prev_global)
+        obs.count("fednc.aggregate_traces", 0)   # reads 0 when warm
+        agg = _block_mean(tuple(out.packets), np.concatenate(ws),
+                          np.float32(0),
+                          tuple(prev[i] for g in layout.groups
+                                for i in g.leaves), spec, layout)
+    return RoundResult(agg, True, out.report, K)
+
+
 def fednc_round(client_params: Sequence[Any], weights: Sequence[float],
                 prev_global: Any, cfg: FedNCConfig, key,
                 channel=None) -> RoundResult:
     """Full Alg.-1 round: a thin adapter over CodingEngine.round().
 
+    A client that trained only some blocks of a leaf (routed experts)
+    gives that leaf as :class:`packets.Blocks`, its rows of the blocks it
+    holds.  The round then codes the leaves every client holds as one
+    generation among all K, and each block somebody holds as its own
+    generation among its holders (``CodingEngine.round`` over a list).
+    The aggregate averages whole leaves over all K and each block over
+    its holders, with weights ``w_k / Σ_{k holding it} w_k`` in float32;
+    a block nobody holds takes `prev_global`'s value.  A grid whose every
+    block every client holds is a whole leaf, so such a round is today's
+    single-generation round.
+
     Each call leaves one ``fednc.round`` record in ``repro.obs``: the
-    host time of its phases (``fednc.packetize``, ``engine.*``,
-    ``fednc.sync``, ``fednc.aggregate``) and its counts."""
+    host time of its phases (``fednc.packetize``, ``fednc.blocks``,
+    ``engine.*``, ``fednc.sync``, ``fednc.aggregate``) and its counts."""
     with obs.record("fednc.round"):
+        if any(pkt.is_blocks(x) for t in client_params
+               for x in jax.tree_util.tree_leaves(t, is_leaf=pkt.is_blocks)):
+            layout, dense, rows = pkt.split_blocks(list(client_params))
+            if layout.groups:
+                return _block_round(layout, dense, rows, weights,
+                                    prev_global, cfg, key, channel)
+            client_params = [jax.tree_util.tree_unflatten(layout.treedef, d)
+                             for d in dense]
         engine = engine_for(cfg)
         P, spec, qspecs = _packetize(client_params, cfg)
         K = P.shape[0]

@@ -55,7 +55,7 @@ from repro.core.rlnc import EncodedBatch, SeededBatch
 from .defaults import DEFAULT_CHUNK_L
 from .registry import (is_seeded_kernel, materialized_kernel_name,
                        resolve_kernel, seeded_kernel_name)
-from .select import incremental_select
+from .select import incremental_select, select_invert_padded
 
 
 def _is_seed_rows(A) -> bool:
@@ -103,6 +103,15 @@ def _chunk_plan(lanes: int, width: int, wrap=None):
     return count, take, join
 
 
+@functools.lru_cache(maxsize=None)
+def _put_chunk(width: int):
+    """Write the first `width` lanes of a chunk's output back into the
+    payload at lane `start`, in place: the payload is donated, so a
+    stream decoded chunk by chunk never holds a second payload."""
+    return jax.jit(lambda X, Y, start: jax.lax.dynamic_update_slice_in_dim(
+        X, Y[:, :width], start, axis=1), donate_argnums=0)
+
+
 @dataclass(frozen=True)
 class EngineConfig:
     """Everything the coding spine needs, in one hashable record."""
@@ -121,7 +130,9 @@ class EngineRound:
     """Outcome of one engine round (the coded math, pre-aggregation)."""
 
     ok: bool
-    packets: Optional[jnp.ndarray]   # (K, L) decoded symbols when ok
+    # (K, L) decoded symbols when ok; a list of them, one per generation,
+    # for a round over several generations
+    packets: Any
     report: Any = None               # ChannelReport when a channel ran
     # redundant-rank cross-check (round(verify=True)): True = every
     # redundant delivered tuple is consistent with the decode, False =
@@ -296,7 +307,7 @@ class CodingEngine:
                             stage=stage)
 
     def _stream(self, A, P, A_post=None, *, enc_seeded: bool = False,
-                stage: str = "encode"):
+                stage: str = "encode", in_place: bool = False):
         """Run the kernel chunk-by-chunk over the lane dim of P.
 
         With `A_post` (the decode mixing matrix), each chunk is pushed
@@ -311,6 +322,11 @@ class CodingEngine:
         so the coding matrix never rides along with the payload.  The
         `A_post` mixing (decode) product always uses the materialized
         kernel.
+
+        With ``in_place`` (a square `A_post`, one device) each decoded
+        chunk is written back over the chunk of P it came from, which
+        consumes P: the stream then holds one payload, not P, the chunk
+        outputs and their join.
 
         One host span, ``engine.<stage>``, covers slicing, dispatching
         and joining every chunk; it never waits on the device.
@@ -343,11 +359,19 @@ class CodingEngine:
                 P = place(jnp.pad(P, ((0, 0), (0, Lp - L))) if Lp != L else P)
             else:
                 nc, take, join = _chunk_plan(L, cl)
+            in_place = in_place and shards == 1 and A_post is not None \
+                and n_out == P.shape[0]
             outs = []
             for c in range(nc):
                 enc = mm(enc_kernel, A, take(P, c))
-                outs.append(mm(post_kernel, A_post, enc)
-                            if A_post is not None else enc)
+                out = mm(post_kernel, A_post, enc) \
+                    if A_post is not None else enc
+                if in_place:
+                    P = _put_chunk(min(cl, L - c * cl))(P, out, c * cl)
+                else:
+                    outs.append(out)
+            if in_place:
+                return P
             out = join(*outs)
             return out[:, :L] if out.shape[1] != L else out
 
@@ -746,11 +770,99 @@ class CodingEngine:
                                              verify)
         return self._stagewise_channel_round(P, A, channel, verify)
 
+    # -- a round over several generations ---------------------------------
+
+    def _generations_solver(self, Ks: tuple, ns: tuple):
+        """The jitted coding draw, selection and inversion of a round's
+        generations, built once per (Ks, ns).
+
+        ``solve(key, idx, valid)``: generation g draws its (n_g, K_g)
+        coding matrix (or n_g seeds) from ``fold_in(key, g)``; its
+        delivered rows are ``idx[g][valid[g]]``.  Every generation is
+        padded to (max n, max K) and selected and inverted in one vmapped
+        pass (:func:`select.select_invert_padded`).  Returns (ok: every
+        generation reached rank, encode rows per generation — (K_g, K_g)
+        matrices or (K_g,) seeds —, (K_g, K_g) inverses)."""
+        key_ = ("generations", Ks, ns)
+        if key_ in self._dispatch:
+            return self._dispatch[key_]
+        s, seeded = self.config.s, self.seeded
+        k_max, n_max = max(Ks), max(ns)
+
+        def solve(key, idx, valid):
+            rows, padded = [], []
+            for g, (K, n) in enumerate(zip(Ks, ns, strict=True)):
+                kg = jax.random.fold_in(key, g)
+                if seeded:
+                    r = self.coding_seeds(kg, n)
+                    A = seedlib.expand_rows(r, K, s)
+                else:
+                    r = A = self.coding_matrix(kg, n, K)
+                rows.append(r)
+                padded.append(jnp.pad(A, ((0, n_max - n), (0, k_max - K))))
+            A_rx = jnp.take_along_axis(jnp.stack(padded), idx[..., None],
+                                       axis=1)
+            A_rx = jnp.where(valid[..., None], A_rx, jnp.uint8(0))
+            ok, sel, A_inv = jax.vmap(
+                lambda A, k: select_invert_padded(A, k, s))(
+                    A_rx, jnp.asarray(Ks, jnp.int32))
+            enc = tuple(rows[g][idx[g, sel[g, :K]]]
+                        for g, K in enumerate(Ks))
+            post = tuple(A_inv[g, :K, :K] for g, K in enumerate(Ks))
+            return jnp.all(ok), enc, post
+
+        self._dispatch[key_] = jax.jit(solve)
+        return self._dispatch[key_]
+
+    def _generations_round(self, Ps: Sequence[jnp.ndarray], key,
+                           channel=None) -> EngineRound:
+        """One round over several generations of unequal K (see
+        :meth:`round`).  Consumes `Ps`: each is decoded in place."""
+        if self.mesh is not None:
+            raise NotImplementedError("generations run on one device")
+        s = self.config.s
+        Ks = tuple(int(P.shape[0]) for P in Ps)
+        ns = tuple(K + self.config.extra_tuples for K in Ks)
+        obs.count("engine.generations", len(Ps))
+        obs.count("engine.coded_symbols",
+                  sum(K * int(P.shape[1]) for K, P in zip(Ks, Ps)))
+        with obs.span("engine.transform", cat="engine", n=sum(ns)):
+            if channel is None:
+                got = [np.arange(n) for n in ns]
+            else:
+                plans = [channel.plan_transform(n, s) for n in ns]
+                if not all(isinstance(p, RowGather) for p in plans):
+                    raise TypeError("generations take erasure channels "
+                                    "(RowGather plans) only")
+                got = [np.asarray(p.idx, np.int64) for p in plans]
+        delivered = sum(len(g) for g in got)
+        if any(len(g) < K for g, K in zip(got, Ks, strict=True)):
+            return EngineRound(False, None,
+                               ChannelReport(sum(ns), delivered, False))
+        n_max = max(ns)
+        idx = np.zeros((len(Ks), n_max), np.int32)
+        valid = np.zeros((len(Ks), n_max), bool)
+        for g, rows in enumerate(got):
+            idx[g, :len(rows)] = rows
+            valid[g, :len(rows)] = True
+        with obs.span("engine.solve", cat="engine", generations=len(Ks)):
+            ok, enc, post = self._generations_solver(Ks, ns)(
+                key, jnp.asarray(idx), jnp.asarray(valid))
+        ok = _on_host(ok)
+        report = ChannelReport(sum(ns), delivered, ok)
+        if not ok:
+            return EngineRound(False, None, report)
+        out = [self._stream(e, P, A_post=a, enc_seeded=self.seeded,
+                            stage="decode", in_place=True)
+               for e, P, a in zip(enc, Ps, post, strict=True)]
+        return EngineRound(True, out, report)
+
     # -- the full round ---------------------------------------------------
 
-    def round(self, P: jnp.ndarray, key, channel=None, *,
+    def round(self, P, key, channel=None, *,
               verify: bool = False) -> EngineRound:
-        """encode -> (channel) -> select -> decode for one packet matrix.
+        """encode -> (channel) -> select -> decode for one packet matrix,
+        or for a list of them: one generation each.
 
         Ideal channel (None): the coding matrix is drawn, selected, and
         inverted *before* any L-sized work, then encode and decode of
@@ -766,7 +878,27 @@ class CodingEngine:
         >>> out = eng.round(P, jax.random.PRNGKey(0))
         >>> out.ok and (out.packets == P).all().item()
         True
+
+        A list of (K_g, L_g) packet matrices is a round over several
+        generations of unequal K (clients that upload only some parameter
+        blocks): generation g sends K_g + ``extra_tuples`` tuples, coded
+        from ``fold_in(key, g)``, with its own erasure pattern drawn in
+        order from the same channel (erasure channels only).  Every
+        generation is selected and inverted in one pass (span
+        ``engine.solve``); the round decodes when every generation reaches
+        rank, and then streams each generation's encode and decode,
+        decoding in place (the matrices are consumed).  Counts
+        ``engine.generations`` and ``engine.coded_symbols`` (Σ K_g·L_g).
+
+        >>> out = eng.round([P[:1], P], jax.random.PRNGKey(1))
+        >>> out.ok, [q.shape for q in out.packets]
+        (True, [(1, 4), (3, 4)])
         """
+        if isinstance(P, (list, tuple)):
+            if verify:
+                raise NotImplementedError("verify takes one generation")
+            with obs.span("engine.round", cat="engine", generations=len(P)):
+                return self._generations_round(P, key, channel)
         K, L = P.shape
         n = K + self.config.extra_tuples
         with obs.span("engine.round", cat="engine", K=K, L=L, n=n):

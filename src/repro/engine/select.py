@@ -25,7 +25,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.core.gf import get_field
+from repro.core.gf import get_field, invert
 
 
 def reduce_insert(field, B, Y, filled, a, c):
@@ -120,3 +120,39 @@ def incremental_select(A: jnp.ndarray, s: int):
     (True, [0, 2], 2)
     """
     return _select_fn(s)(A)
+
+
+def select_invert_padded(A: jnp.ndarray, k, s: int):
+    """Select and invert one generation padded to (n, K_max).
+
+    Rows of A past the generation's delivered ones, and its columns
+    ``>= k``, are zero.  The masked columns start as filled pivots with
+    identity basis rows, so the elimination (:func:`reduce_insert`)
+    picks k independent rows among the real ones, exactly as
+    :func:`incremental_select` does on the unpadded (n, k) matrix; the
+    padded selection ``[[A_sel, 0], [0, I]]`` then inverts to
+    ``[[A_sel^-1, 0], [0, I]]``.  Traced (jit- and vmap-safe, `k` may be
+    traced): the engine maps it over a round's generations.
+
+    Returns ``(ok, idx, A_inv)``: `idx` (K_max,) the selected rows in
+    scan order (positions ``>= k`` are 0), `A_inv` (K_max, K_max).
+    """
+    field = get_field(s)
+    n, km = A.shape
+    masked = jnp.arange(km) >= k
+    eye = jnp.eye(km, dtype=jnp.uint8)
+    c0 = jnp.zeros((0,), jnp.uint8)
+
+    def body(i, state):
+        B, Y, filled, sel, count = state
+        B, Y, filled, found, _ = reduce_insert(field, B, Y, filled, A[i], c0)
+        sel = jnp.where(found, sel.at[count].set(i), sel)
+        return B, Y, filled, sel, count + found.astype(jnp.int32)
+
+    state = (jnp.where(masked[:, None], eye, jnp.uint8(0)),
+             jnp.zeros((km, 0), jnp.uint8), masked,
+             jnp.zeros((km,), jnp.int32), jnp.int32(0))
+    _, _, _, sel, count = jax.lax.fori_loop(0, n, body, state)
+    square = jnp.where((jnp.arange(km) < k)[:, None], A[sel], eye)
+    ok_inv, A_inv = invert(field, square)
+    return (count == k) & ok_inv, sel, A_inv

@@ -61,10 +61,15 @@ def _init_mla(key, cfg: ModelConfig) -> dict:
     d, H = cfg.d_model, cfg.num_heads
     ks = jax.random.split(key, 8)
     qd = m.nope_head_dim + m.rope_head_dim
+    if m.q_lora_rank is None:
+        q = {"w_q": dense_init(ks[0], d, H * qd, dtype=cfg.dtype)}
+    else:
+        q = {"w_dq": dense_init(ks[0], d, m.q_lora_rank, dtype=cfg.dtype),
+             "q_norm": norm_init(m.q_lora_rank, "rmsnorm", cfg.dtype),
+             "w_uq": dense_init(ks[1], m.q_lora_rank, H * qd,
+                                dtype=cfg.dtype)}
     return {
-        "w_dq": dense_init(ks[0], d, m.q_lora_rank, dtype=cfg.dtype),
-        "q_norm": norm_init(m.q_lora_rank, "rmsnorm", cfg.dtype),
-        "w_uq": dense_init(ks[1], m.q_lora_rank, H * qd, dtype=cfg.dtype),
+        **q,
         "w_dkv": dense_init(ks[2], d, m.kv_lora_rank, dtype=cfg.dtype),
         "kv_norm": norm_init(m.kv_lora_rank, "rmsnorm", cfg.dtype),
         "w_uk": dense_init(ks[3], m.kv_lora_rank, H * m.nope_head_dim,
@@ -327,9 +332,13 @@ def _apply_mla(p: dict, x: jnp.ndarray, cfg: ModelConfig, *,
         base = cache["pos"] if cache is not None else 0
         positions = base + jnp.arange(S)[None, :]
 
-    # queries: low-rank then up
-    cq = norm_apply(p["q_norm"], dense_apply(p["w_dq"], x))
-    q = dense_apply(p["w_uq"], cq).reshape(B, S, H, nd + rd)
+    # queries: low-rank then up, or one projection (q_lora_rank=None)
+    if "w_q" in p:
+        q = dense_apply(p["w_q"], x)
+    else:
+        q = dense_apply(p["w_uq"],
+                        norm_apply(p["q_norm"], dense_apply(p["w_dq"], x)))
+    q = q.reshape(B, S, H, nd + rd)
     q_nope, q_rope = q[..., :nd], q[..., nd:]
     q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
 

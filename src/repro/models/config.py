@@ -33,9 +33,12 @@ class MLAConfig:
     absorbed=True scores in latent space (q absorbed through W_uk,
     output combined through W_uv) — K/V are never expanded to
     (B, T, H, head_dim).  More score FLOPs (latent rank vs head_dim),
-    far less memory traffic: the §Perf memory-bound variant."""
+    far less memory traffic: the §Perf memory-bound variant.
+
+    q_lora_rank=None drops the query compression (DeepSeek-V2-Lite):
+    one projection maps d_model to H·(nope + rope) directly."""
     kv_lora_rank: int = 512
-    q_lora_rank: int = 1536
+    q_lora_rank: Optional[int] = 1536
     nope_head_dim: int = 128
     rope_head_dim: int = 64
     v_head_dim: int = 128
@@ -44,6 +47,14 @@ class MLAConfig:
 
 @dataclass(frozen=True)
 class MoEConfig:
+    """Routed experts (+ shared / residual MLPs).
+
+    `norm_topk_prob` renormalizes the top-k gates to sum to 1 (False:
+    the raw softmax probabilities weight the experts, DeepSeek-V2-Lite).
+    `held_experts` are the ids of the experts this layer holds (expert
+    parallelism: one rank's share); None holds all `num_experts`.  The
+    router always scores all `num_experts`; the layer adds only its held
+    experts' part of the routed output."""
     num_experts: int = 8
     top_k: int = 2
     d_ff_expert: int = 0            # per-expert hidden dim
@@ -52,6 +63,15 @@ class MoEConfig:
     d_ff_residual: int = 0          # hidden of the residual/shared MLP
     capacity_factor: float = 1.25
     router_aux_weight: float = 0.01
+    norm_topk_prob: bool = True
+    held_experts: Optional[tuple[int, ...]] = None
+
+    @property
+    def held(self) -> tuple[int, ...]:
+        """The ids of the experts this layer holds, ascending."""
+        if self.held_experts is None:
+            return tuple(range(self.num_experts))
+        return tuple(self.held_experts)
 
 
 @dataclass(frozen=True)

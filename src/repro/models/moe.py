@@ -10,7 +10,10 @@ compiled FLOPs stay honest for the roofline table.
 
 Expert weights carry a leading E dim that shards over the `model` mesh
 axis (expert parallelism); XLA SPMD lowers dispatch/combine into
-all-to-alls / reduce-scatters on that axis.
+all-to-alls / reduce-scatters on that axis.  A layer told which experts
+it holds (`MoEConfig.held_experts`, one rank's share) keeps only those
+experts' weights and adds only their part of the routed output; the
+router still scores, and capacity still counts, all experts.
 """
 from __future__ import annotations
 
@@ -51,15 +54,16 @@ def _constrain(x):
 def init_moe(key, cfg: ModelConfig) -> dict:
     mc: MoEConfig = cfg.moe
     d, ff, E = cfg.d_model, mc.d_ff_expert, mc.num_experts
+    H = len(mc.held)            # expert leaves hold this rank's experts
     ks = jax.random.split(key, 6)
     scale = 1.0 / np.sqrt(d)
     p = {
         "router": dense_init(ks[0], d, E, scale=0.02, dtype=jnp.float32),
-        "w_gate": (jax.random.normal(ks[1], (E, d, ff), jnp.float32)
+        "w_gate": (jax.random.normal(ks[1], (H, d, ff), jnp.float32)
                    * scale).astype(cfg.dtype),
-        "w_up": (jax.random.normal(ks[2], (E, d, ff), jnp.float32)
+        "w_up": (jax.random.normal(ks[2], (H, d, ff), jnp.float32)
                  * scale).astype(cfg.dtype),
-        "w_down": (jax.random.normal(ks[3], (E, ff, d), jnp.float32)
+        "w_down": (jax.random.normal(ks[3], (H, ff, d), jnp.float32)
                    / np.sqrt(ff)).astype(cfg.dtype),
     }
     if mc.num_shared_experts > 0:
@@ -87,7 +91,8 @@ def _route_group(p: dict, xt: jnp.ndarray, cfg: ModelConfig
     probs = jax.nn.softmax(logits, axis=-1)
 
     gate_vals, gate_idx = jax.lax.top_k(probs, k)                # (T, k)
-    gate_vals = gate_vals / (gate_vals.sum(-1, keepdims=True) + 1e-9)
+    if mc.norm_topk_prob:
+        gate_vals = gate_vals / (gate_vals.sum(-1, keepdims=True) + 1e-9)
 
     onehot = jax.nn.one_hot(gate_idx, E, dtype=jnp.float32)      # (T,k,E)
     # position of each (token, choice) within its expert's capacity
@@ -101,6 +106,10 @@ def _route_group(p: dict, xt: jnp.ndarray, cfg: ModelConfig
     disp = jnp.einsum("tke,tkc->tec", onehot * keep[..., None],
                       pos_onehot)
     comb = jnp.einsum("tke,tkc,tk->tec", onehot, pos_onehot, gate_vals)
+    if mc.held_experts is not None:
+        # routed over all E; only the held experts' part is computed
+        held = jnp.asarray(mc.held, jnp.int32)
+        disp, comb = disp[:, held], comb[:, held]
 
     xe = _constrain(
         jnp.einsum("tec,td->ecd", disp.astype(cfg.dtype), xt))   # (E,C,d)
